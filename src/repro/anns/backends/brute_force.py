@@ -23,6 +23,9 @@ from repro.anns.search import BIG
 from repro.kernels.distance.ops import pairwise_distance
 from repro.kernels.topk.ops import topk_smallest
 
+#: the anchor is exact: its MXU dot contracts in float32, not a bf16 pass
+EXACT = jax.lax.Precision.HIGHEST
+
 
 @register("brute_force")
 class BruteForceBackend(AttributeColumns):
@@ -67,7 +70,8 @@ class BruteForceBackend(AttributeColumns):
         vals, ids = [], []
         for lo in range(0, n, self.chunk):
             xc = base[lo: lo + self.chunk]
-            d = pairwise_distance(q, xc, metric=self.metric)
+            d = pairwise_distance(q, xc, metric=self.metric,
+                                  precision=EXACT)
             if fmask is not None:
                 d = jnp.where(fmask[lo: lo + self.chunk][None, :], d, BIG)
             v, i = topk_smallest(d, min(k, xc.shape[0]))

@@ -117,7 +117,7 @@ def _ivf_search(centroids, cells, ids, base, base_q, scales, queries,
         vecs = base_q[pos].astype(jnp.float32) * scales[pos][..., None]
     else:
         vecs = base[pos]
-    d = search_lib._qdist(q32, vecs, metric)
+    d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
     d = jnp.where(valid, d, BIG)
 
     _, keep = jax.lax.top_k(-d, m)
@@ -173,8 +173,10 @@ class IvfBackend(AttributeColumns):
             else self.variant.nlist
         return ef_ladder_for_nprobe(self.variant, nlist)
 
-    def search(self, queries, params: SearchParams) -> SearchResult:
-        assert self.index is not None, "build() first"
+    def _invocation(self, queries, params: SearchParams):
+        """Resolve one search call to (positional arrays, static knobs) —
+        shared by :meth:`search` and :meth:`lower_search`, so a compile
+        check inspects exactly the program that serves."""
         idx = self.index
         p = params.resolved(self.variant)
         k = min(p.k, idx.n)
@@ -193,12 +195,26 @@ class IvfBackend(AttributeColumns):
         quantized = True if params.quantized is None else bool(params.quantized)
         fmask = (self._row_mask_dev(p.filter)
                  if p.filter is not None else None)
-        out_ids, out_d, scanned = _ivf_search(
-            idx.centroids, idx.cells, idx.ids, idx.base, idx.base_q,
-            idx.scales, jnp.asarray(queries, jnp.float32), fmask,
-            nprobe=nprobe, k=k, m=m, metric=self.metric, quantized=quantized)
-        return SearchResult(ids=out_ids, dists=out_d, steps=nprobe,
+        args = (idx.centroids, idx.cells, idx.ids, idx.base, idx.base_q,
+                idx.scales, jnp.asarray(queries, jnp.float32), fmask)
+        statics = dict(nprobe=nprobe, k=k, m=m, metric=self.metric,
+                       quantized=quantized)
+        return args, statics
+
+    def search(self, queries, params: SearchParams) -> SearchResult:
+        assert self.index is not None, "build() first"
+        args, statics = self._invocation(queries, params)
+        out_ids, out_d, scanned = _ivf_search(*args, **statics)
+        return SearchResult(ids=out_ids, dists=out_d,
+                            steps=statics["nprobe"],
                             expansions=scanned, backend=self.name)
+
+    def lower_search(self, queries, params: SearchParams):
+        """AOT-lower the jitted search for inspection of the compiled
+        program (e.g. that its kernels lowered to ``tpu_custom_call``)."""
+        assert self.index is not None, "build() first"
+        args, statics = self._invocation(queries, params)
+        return _ivf_search.lower(*args, **statics)
 
     def memory_bytes(self) -> int:
         idx = self.index

@@ -104,7 +104,7 @@ def _scan_rerank_block(shard_id, cells_j, v0_j, bq_j, sc_j, bf_j,
         vecs = bq_j[pos].astype(jnp.float32) * sc_j[pos][..., None]
     else:
         vecs = bf_j[pos]
-    d = search_lib._qdist(q32, vecs, metric)
+    d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
     d = jnp.where(valid, d, BIG)
     nd, keep = jax.lax.top_k(-d, m_shard)
     lpos = jnp.take_along_axis(pos, keep, axis=1)
@@ -173,8 +173,11 @@ def _make_placed_search(mesh):
     ``shard_map`` over the ``"shard"`` axis, so the cross-device traffic
     is *exactly* the shortlist ``all_gather`` ((S, B, m) ids+scores) plus
     a scalar ``psum`` — never an (N, d) broadcast, whatever the
-    partitioner would have chosen for the vmapped form."""
-    from jax.experimental.shard_map import shard_map
+    partitioner would have chosen for the vmapped form.
+
+    The coarse route runs inside the ``shard_map`` too, on every device
+    over the replicated centroids and queries: its Pallas kernels are
+    TPU custom calls, which the partitioner cannot split."""
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(jax.jit, static_argnames=(
@@ -184,38 +187,39 @@ def _make_placed_search(mesh):
                       nprobe: int, k: int, m: int, metric: str,
                       quantized: bool):
         n_shards, _, pad = cells.shape
-        q32, owner, row = _route(centroids, cell_shard, cell_row, queries,
-                                 nprobe=nprobe, metric=metric)
         m_shard = min(m, nprobe * pad)
 
-        def block(cells_b, v0_b, bq_b, sc_b, bf_b, q32_, owner_, row_,
-                  *rest):
+        def block(cents, c_shard, c_row, qs, cells_b, v0_b, bq_b, sc_b,
+                  bf_b, *rest):
             j = jax.lax.axis_index("shard")
             fm_b = rest[0][0] if rest else None
+            q32, owner, row = _route(cents, c_shard, c_row, qs,
+                                     nprobe=nprobe, metric=metric)
             gpos, sd, rd, valid, scanned = _scan_rerank_block(
                 j, cells_b[0], v0_b[0], bq_b[0], sc_b[0], bf_b[0],
-                q32_, owner_, row_, fm_b, m_shard=m_shard, metric=metric,
+                q32, owner, row, fm_b, m_shard=m_shard, metric=metric,
                 quantized=quantized)
             # the merge traffic, in full: (S, B, m_shard) ids+scores
             out = [jax.lax.all_gather(t, "shard")
                    for t in (gpos, sd, rd, valid)]
             return (*out, jax.lax.psum(scanned, "shard"))
 
-        in_specs = (P("shard", None, None), P("shard"),
+        in_specs = (P(), P(), P(), P(),
+                    P("shard", None, None), P("shard"),
                     P("shard", None, None), P("shard", None),
-                    P("shard", None, None), P(), P(), P())
-        operands = (cells, vec_start, base_q, scales, base_f,
-                    q32, owner, row)
+                    P("shard", None, None))
+        operands = (centroids, cell_shard, cell_row, queries,
+                    cells, vec_start, base_q, scales, base_f)
         if fmask is not None:
             # the filter bitmask is shard-local state like the slices:
             # each device ANDs only its own (Npad,) row, no mask traffic
             in_specs += (P("shard", None),)
             operands += (fmask,)
-        gpos, sd, rd, valid, scanned = shard_map(
+        gpos, sd, rd, valid, scanned = jax.shard_map(
             block, mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(), P(), P(), P(), P()),
-            check_rep=False)(*operands)
+            check_vma=False)(*operands)
         m_total = min(m, n_shards * m_shard)
         out_pos, out_d = _merge_topk(gpos, sd, rd, valid,
                                      k=k, m_total=m_total)

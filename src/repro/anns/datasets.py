@@ -145,9 +145,24 @@ def exact_ground_truth(base: np.ndarray, queries: np.ndarray, k: int,
     for i in range(0, len(queries), 512):
         q = jnp.asarray(queries[i:i + 512])
         d = np.asarray(distance_ref(q, b, metric))
-        idx = np.argsort(d, axis=1, kind="stable")[:, :k]
-        out.append(idx)
+        out.append(stable_smallest(d, k))
     return np.concatenate(out, axis=0).astype(np.int32)
+
+
+def stable_smallest(d: np.ndarray, k: int) -> np.ndarray:
+    """Row-wise ids of the ``k`` smallest entries, ties by ascending id:
+    ``np.argsort(d, axis=1, kind="stable")[:, :k]`` without sorting whole
+    rows.  Each row sorts only the entries at or below its k-th smallest
+    value — O(N) per row instead of O(N log N), which is what makes a
+    10^6-vector ground truth cheap on the host."""
+    if k >= d.shape[1]:
+        return np.argsort(d, axis=1, kind="stable")[:, :k]
+    kth = np.partition(d, k - 1, axis=1)[:, k - 1]
+    out = np.empty((len(d), k), np.int64)
+    for r, row in enumerate(d):
+        cand = np.flatnonzero(row <= kth[r])    # ascending ids
+        out[r] = cand[np.argsort(row[cand], kind="stable")[:k]]
+    return out
 
 
 # default attribute columns: {name: cardinality}, values uniform over
@@ -170,7 +185,9 @@ def make_dataset(name: str, n_base: int = 20000, n_query: int = 200,
         base /= np.maximum(np.linalg.norm(base, axis=1, keepdims=True), 1e-9)
         queries /= np.maximum(np.linalg.norm(queries, axis=1, keepdims=True), 1e-9)
     metric = "l2" if spec.metric == "l2" else "ip"
-    gt = exact_ground_truth(base, queries, k_gt, metric)
+    # k_gt=0 leaves the ground truth to the caller (e.g. timed apart)
+    gt = (exact_ground_truth(base, queries, k_gt, metric) if k_gt
+          else np.zeros((n_query, 0), np.int32))
     # attribute columns come from their own salted stream (and are drawn in
     # sorted column order): base/query/gt bytes are identical with or
     # without them, so nothing pinned by golden tests or shipped

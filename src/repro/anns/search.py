@@ -29,8 +29,18 @@ from repro.anns.graph import GraphIndex
 BIG = 3.0e38
 
 
-def _qdist(q: jax.Array, vecs: jax.Array, metric: str) -> jax.Array:
-    dots = jnp.einsum("bd,bcd->bc", q, vecs, preferred_element_type=jnp.float32)
+def _qdist(q: jax.Array, vecs: jax.Array, metric: str, *,
+           quantized: bool = False) -> jax.Array:
+    """(B, d) x (B, C, d) -> (B, C) distances, smaller = closer.
+
+    fp32 vectors score at ``Precision.HIGHEST``: on a TPU a float32 dot at
+    default precision may run as a single bf16 pass, and the exact stages
+    (fp32 scans, reranks, the delta tail) must rank as the CPU does.
+    Dequantised int8 codes (``quantized=True``) are already approximate
+    and keep the default precision."""
+    precision = None if quantized else jax.lax.Precision.HIGHEST
+    dots = jnp.einsum("bd,bcd->bc", q, vecs, precision=precision,
+                      preferred_element_type=jnp.float32)
     if metric == "ip":
         return -dots
     qn = jnp.sum(q * q, axis=-1)[:, None]
@@ -58,7 +68,7 @@ def _beam_search(
         vecs0 = base_q[init_ids].astype(jnp.float32) * scales[init_ids][..., None]
     else:
         vecs0 = base[init_ids]
-    d0 = _qdist(q32, vecs0, metric)
+    d0 = _qdist(q32, vecs0, metric, quantized=quantized)
 
     pad = ef - E
     beam_ids = jnp.concatenate(
@@ -113,7 +123,7 @@ def _beam_search(
             vecs = base_q[cand].astype(jnp.float32) * scales[cand][..., None]
         else:
             vecs = base[cand]
-        dc = _qdist(q32, vecs, metric)
+        dc = _qdist(q32, vecs, metric, quantized=quantized)
         dc = jnp.where(fresh, dc, BIG)
 
         # 4. merge into beam

@@ -79,7 +79,7 @@ def stream_ivf_search(centroids, cells, base, base_q, scales, live,
         vecs = base_q[pos].astype(jnp.float32) * scales[pos][..., None]
     else:
         vecs = base[pos]
-    d = search_lib._qdist(q32, vecs, metric)
+    d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
     d = jnp.where(valid, d, BIG)
 
     _, keep = jax.lax.top_k(-d, m)
@@ -121,7 +121,7 @@ def _stream_scan_block(shard_id, cells_j, v0_j, bq_j, sc_j, bf_j, live_j,
         vecs = bq_j[pos].astype(jnp.float32) * sc_j[pos][..., None]
     else:
         vecs = bf_j[pos]
-    d = search_lib._qdist(q32, vecs, metric)
+    d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
     d = jnp.where(valid, d, BIG)
     nd, keep = jax.lax.top_k(-d, m_shard)
     lpos = jnp.take_along_axis(pos, keep, axis=1)
@@ -204,8 +204,9 @@ def make_placed_stream_search(mesh):
     ``all_gather`` — now carrying the (S, B, cap) tail distances too —
     plus the scalar ``psum``.  Mutable leaves (live mask, tail arrays)
     are sharded like the base slices, so a mutation never moves base
-    bytes between devices."""
-    from jax.experimental.shard_map import shard_map
+    bytes between devices.  As in ``backends.sharded``, the coarse route
+    runs inside the ``shard_map`` on every device: its Pallas kernels
+    cannot be partitioned automatically."""
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(jax.jit, static_argnames=(
@@ -218,31 +219,32 @@ def make_placed_stream_search(mesh):
         n_shards, _, pad = cells.shape
         cap = tail_vecs.shape[1]
         n = ids_ext.shape[0] - n_shards * cap
-        q32, owner, row = _route(centroids, cell_shard, cell_row, queries,
-                                 nprobe=nprobe, metric=metric)
         m_shard = min(m, nprobe * pad)
 
-        def block(cells_b, v0_b, bq_b, sc_b, bf_b, live_b, tv_b, tl_b,
-                  q32_, owner_, row_):
+        def block(cents, c_shard, c_row, qs, cells_b, v0_b, bq_b, sc_b,
+                  bf_b, live_b, tv_b, tl_b):
             j = jax.lax.axis_index("shard")
+            q32, owner, row = _route(cents, c_shard, c_row, qs,
+                                     nprobe=nprobe, metric=metric)
             gpos, sd, rd, valid, td, scanned = _stream_scan_block(
                 j, cells_b[0], v0_b[0], bq_b[0], sc_b[0], bf_b[0],
-                live_b[0], tv_b[0], tl_b[0], q32_, owner_, row_,
+                live_b[0], tv_b[0], tl_b[0], q32, owner, row,
                 m_shard=m_shard, metric=metric, quantized=quantized)
             out = [jax.lax.all_gather(t, "shard")
                    for t in (gpos, sd, rd, valid, td)]
             return (*out, jax.lax.psum(scanned, "shard"))
 
-        gpos, sd, rd, valid, td, scanned = shard_map(
+        gpos, sd, rd, valid, td, scanned = jax.shard_map(
             block, mesh=mesh,
-            in_specs=(P("shard", None, None), P("shard"),
+            in_specs=(P(), P(), P(), P(),
+                      P("shard", None, None), P("shard"),
                       P("shard", None, None), P("shard", None),
                       P("shard", None, None), P("shard", None),
-                      P("shard", None, None), P("shard", None),
-                      P(), P(), P()),
+                      P("shard", None, None), P("shard", None)),
             out_specs=(P(), P(), P(), P(), P(), P()),
-            check_rep=False)(cells, vec_start, base_q, scales, base_f,
-                             live, tail_vecs, tail_live, q32, owner, row)
+            check_vma=False)(centroids, cell_shard, cell_row, queries,
+                             cells, vec_start, base_q, scales, base_f,
+                             live, tail_vecs, tail_live)
         m_total = min(m, n_shards * m_shard)
         out_ids, out_d = _stream_merge_topk(gpos, sd, rd, valid, td,
                                             ids_ext, k=k, m_total=m_total,

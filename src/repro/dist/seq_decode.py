@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -2.0 ** 30  # matches repro.models.attention masking
@@ -97,13 +96,13 @@ def seq_sharded_decode(q, k, v, cache, cache_len, *, window: int,
 
     rep = P(None, None, None, None)          # replicated over every axis
     seq = P(None, "model", None, None)       # cache layout
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_, kc, vc, s_, n_: _decode_update_and_attend(
             q_, k_, v_, kc, vc, s_, n_, q_scale=q_scale, softcap=softcap,
             axis="model"),
         mesh=mesh,
         in_specs=(rep, rep, rep, seq, seq, P(), P()),
         out_specs=(rep, seq, seq),
-        check_rep=False)
+        check_vma=False)
     o, nk, nv = fn(q, k, v, cache["k"], cache["v"], slot, valid)
     return o, {"k": nk, "v": nv}

@@ -3,14 +3,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat constructor: the params class was renamed
-    TPUCompilerParams -> CompilerParams across jax releases."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
 
 
 def interpret_default() -> bool:

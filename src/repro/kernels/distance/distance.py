@@ -16,10 +16,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import tpu_compiler_params
 
-
-def _kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, acc_ref, *, nd: int, metric: str):
+def _kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, acc_ref, *, nd: int,
+            metric: str, precision):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -27,6 +26,7 @@ def _kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, acc_ref, *, nd: int, metric: st
     acc_ref[...] += jax.lax.dot_general(
         q_ref[...], x_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
 
@@ -42,7 +42,8 @@ def _kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, acc_ref, *, nd: int, metric: st
 
 
 @functools.partial(
-    jax.jit, static_argnames=("metric", "bq", "bx", "bd", "interpret"))
+    jax.jit,
+    static_argnames=("metric", "bq", "bx", "bd", "precision", "interpret"))
 def distance(
     q: jax.Array,              # (nq, d)
     x: jax.Array,              # (nx, d)
@@ -51,6 +52,7 @@ def distance(
     bq: int = 128,
     bx: int = 128,
     bd: int = 128,
+    precision: jax.lax.Precision | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     nq, d = q.shape
@@ -63,7 +65,8 @@ def distance(
 
     grid = (nq // bq, nx // bx, nd)
     return pl.pallas_call(
-        functools.partial(_kernel, nd=nd, metric=metric),
+        functools.partial(_kernel, nd=nd, metric=metric,
+                          precision=precision),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, bd), lambda i, j, k: (i, k)),
@@ -74,7 +77,7 @@ def distance(
         out_specs=pl.BlockSpec((bq, bx), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq, nx), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, bx), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, x, qn, xn)

@@ -12,15 +12,20 @@ from repro.kernels.distance.distance import distance as _distance_kernel
 from repro.kernels.distance.ref import distance_ref
 
 
-@functools.partial(jax.jit, static_argnames=("metric", "use_kernel"))
+@functools.partial(jax.jit,
+                   static_argnames=("metric", "precision", "use_kernel"))
 def pairwise_distance(
     q: jax.Array,
     x: jax.Array,
     *,
     metric: str = "l2",
+    precision: jax.lax.Precision | None = None,
     use_kernel: bool | None = None,
 ) -> jax.Array:
-    """(nq, d) x (nx, d) -> (nq, nx) fp32; smaller = closer for both metrics."""
+    """(nq, d) x (nx, d) -> (nq, nx) fp32; smaller = closer for both metrics.
+
+    ``precision`` is the kernel's MXU contract precision: ``None`` keeps
+    Mosaic's default, ``Precision.HIGHEST`` contracts in float32."""
     if use_kernel is None:
         use_kernel = True
     if not use_kernel:
@@ -36,5 +41,5 @@ def pairwise_distance(
     xp = pad_dim(x, 0, round_up(nx, bx))
     xp = pad_dim(xp, 1, round_up(d, bd))
     out = _distance_kernel(qp, xp, metric=metric, bq=bq, bx=bx, bd=bd,
-                           interpret=interpret_default())
+                           precision=precision, interpret=interpret_default())
     return out[:nq, :nx]

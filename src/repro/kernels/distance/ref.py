@@ -1,6 +1,7 @@
 """Pure-jnp oracle for the batched distance-matrix kernel."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -8,11 +9,13 @@ def distance_ref(q: jnp.ndarray, x: jnp.ndarray, metric: str = "l2") -> jnp.ndar
     """q: (nq, d), x: (nx, d) -> (nq, nx) fp32 distances.
 
     l2: squared euclidean.  ip: negative inner product (smaller = closer),
-    which is angular distance when inputs are unit-normalised.
+    which is angular distance when inputs are unit-normalised.  The dot
+    runs at ``Precision.HIGHEST`` so the oracle (and the ground truth built
+    on it) is float32 on a TPU too, not a single bf16 pass.
     """
     qf = q.astype(jnp.float32)
     xf = x.astype(jnp.float32)
-    dots = qf @ xf.T
+    dots = jnp.matmul(qf, xf.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return -dots
     qn = jnp.sum(qf * qf, axis=1, keepdims=True)

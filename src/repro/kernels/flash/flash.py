@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import tpu_compiler_params
-
 NEG_INF = -2.0 ** 30
 
 
@@ -115,7 +113,7 @@ def flash_attention(
             pltpu.VMEM((bq * G, 1), jnp.float32),
             pltpu.VMEM((bq * G, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, k, v)

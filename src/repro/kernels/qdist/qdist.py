@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import tpu_compiler_params
-
 
 def _kernel(q_ref, x_ref, s_ref, qn_ref, xn_ref, o_ref, acc_ref, *,
             nd: int, metric: str):
@@ -80,7 +78,7 @@ def qdist(
         out_specs=pl.BlockSpec((bq, bx), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq, nx), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, bx), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, xq, s2, qn, xn)

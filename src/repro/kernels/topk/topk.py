@@ -22,6 +22,9 @@ def _kernel(d_ref, vals_ref, idx_ref, *, k: int):
     d = d_ref[...].astype(jnp.float32)              # (BQ, NX)
     bq, nx = d.shape
     col = jax.lax.broadcasted_iota(jnp.int32, (bq, nx), 1)
+    # output slot j is written by a select against a lane iota: Mosaic has
+    # no lowering for a dynamic_update_slice along the lane axis
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
 
     def body(j, carry):
         d_cur, vals, idxs = carry
@@ -29,8 +32,8 @@ def _kernel(d_ref, vals_ref, idx_ref, *, k: int):
         # lowest index attaining the min (tie-break like lax.top_k)
         is_min = d_cur <= m[:, None]
         a = jnp.min(jnp.where(is_min, col, nx), axis=1).astype(jnp.int32)
-        vals = jax.lax.dynamic_update_index_in_dim(vals, m, j, axis=1)
-        idxs = jax.lax.dynamic_update_index_in_dim(idxs, a, j, axis=1)
+        vals = jnp.where(slot == j, m[:, None], vals)
+        idxs = jnp.where(slot == j, a[:, None], idxs)
         d_cur = jnp.where(col == a[:, None], BIG, d_cur)
         return d_cur, vals, idxs
 

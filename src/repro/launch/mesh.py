@@ -11,17 +11,28 @@ over ("pod", "data"); TP/EP collectives stay inside the pod's "model" axis
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis Auto: the partitioner places
+    what the code's shardings leave open.  jax's default is Explicit
+    axes, under which a gather such as the embedding lookup refuses an
+    operand sharded along the gathered axis."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CPU tests (requires forced host device count)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_shard_mesh(n_shards: int):
@@ -30,16 +41,7 @@ def make_shard_mesh(n_shards: int):
     fp32 rerank slice ``base_f``, so per-device memory is O(N/S * d)
     (``repro.anns.ivf.sharding.place_on_mesh``).  CPU tests force host
     devices via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
-    return jax.make_mesh((n_shards,), ("shard",))
-
-
-def shard_mesh_if_available(n_shards: int):
-    """:func:`make_shard_mesh` when the runtime has enough devices for
-    one shard per device, else ``None`` — the caller falls back to the
-    single-device unrolled search (identical results, no placement)."""
-    if n_shards > 1 and jax.device_count() >= n_shards:
-        return make_shard_mesh(n_shards)
-    return None
+    return auto_mesh((n_shards,), ("shard",))
 
 
 def make_tuned_mesh(tp: int = 16, *, multi_pod: bool = False):
@@ -54,6 +56,6 @@ def make_tuned_mesh(tp: int = 16, *, multi_pod: bool = False):
     """
     assert 16 % tp == 0
     if multi_pod:
-        return jax.make_mesh((2, 16, 16 // tp, tp),
-                             ("pod", "data", "replica", "model"))
-    return jax.make_mesh((16, 16 // tp, tp), ("data", "replica", "model"))
+        return auto_mesh((2, 16, 16 // tp, tp),
+                         ("pod", "data", "replica", "model"))
+    return auto_mesh((16, 16 // tp, tp), ("data", "replica", "model"))

@@ -686,6 +686,7 @@ def main():
 
     import numpy as np
     from repro import ckpt
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.anns import SearchParams, make_dataset, registry
     from repro.anns.datasets import recall_at_k
     from repro.anns.engine import GLASS_BASELINE, VariantConfig
@@ -694,6 +695,7 @@ def main():
     if args.backend not in registry.available():
         ap.error(f"unknown backend {args.backend!r}; "
                  f"registered: {registry.available()}")
+    enable_compile_cache()
 
     ds = make_dataset(args.dataset, n_base=args.n_base, n_query=args.n_query)
     variant = GLASS_BASELINE
@@ -736,15 +738,18 @@ def main():
                      f"read-only")
 
     if getattr(target, "name", "") in ("sharded", "stream_sharded"):
-        from repro.launch.mesh import shard_mesh_if_available
+        import jax
+        from repro.launch.mesh import make_shard_mesh
         ns = target.index.n_shards
-        mesh = shard_mesh_if_available(ns)
-        if mesh is not None:
+        if ns > 1 and jax.device_count() >= ns:
             # each device holds only its cell shard; run with
             # XLA_FLAGS=--xla_force_host_platform_device_count=N on CPU
-            target.place_on_mesh(mesh)
+            target.place_on_mesh(make_shard_mesh(ns))
             print(f"placed {ns} cell shards on {ns} devices "
                   f"({target.device_memory_bytes()/1e6:.1f} MB/device)")
+        elif ns > 1:
+            print(f"note: {jax.device_count()} device(s) for {ns} shards — "
+                  f"serving the unplaced one-device program")
 
     if args.filter or args.filter_demo:
         # a restored index may already carry its attribute columns

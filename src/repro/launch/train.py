@@ -40,7 +40,7 @@ def main():
     from repro.core.grpo import GRPOConfig
     from repro.data import PromptPipeline
     from repro.dist.sharding import param_shardings
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_debug_mesh, make_production_mesh
     from repro.models import model as model_lib
     from repro.models.runtime import Runtime
     from repro.runtime import Trainer, TrainerConfig
@@ -52,7 +52,7 @@ def main():
         mesh = make_production_mesh()
     elif args.debug_mesh:
         d, m = (int(x) for x in args.debug_mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_debug_mesh(d, m)
 
     rt = Runtime(mesh=mesh, attn_chunk=min(512, args.seq),
                  logit_chunk=min(512, args.seq), remat="block")

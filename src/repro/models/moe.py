@@ -30,8 +30,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-# jax 0.4.37: shard_map lives in jax.experimental (not yet jax.shard_map)
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -148,13 +146,13 @@ def apply_moe(
         batch_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0], None)
     else:
         batch_spec = P(None, None)   # tiny decode batches: replicate tokens
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(ep_axis, None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None), batch_spec),
         out_specs=(batch_spec, P()),
-        check_rep=False,   # jax 0.4.37 name for check_vma
+        check_vma=False,
     )
     out, aux = fn(p["router"], p["w_gate"], p["w_in"], p["w_out"], xf)
     return out.reshape(B, S, d).astype(x.dtype), aux

@@ -24,6 +24,7 @@ def test_sharded_train_step_matches_single_device():
     """Loss on a 2x4 mesh must equal the unsharded loss (same params/batch)."""
     out = _run("""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.models import model, Runtime
 from repro.core.grpo import GRPOConfig, grpo_loss
@@ -32,7 +33,7 @@ from repro.launch.specs import train_specs
 
 cfg = dataclasses.replace(get_config('deepseek-moe-16b', reduced=True),
                           dtype='float32', vocab_size=256)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = auto_mesh((2, 4), ('data', 'model'))
 params = model.init_params(jax.random.PRNGKey(0), cfg)
 B, S = 4, 32
 batch = {
@@ -64,12 +65,13 @@ def test_moe_shard_map_matches_local():
     """EP shard_map MoE == local dispatch (fp32, high capacity)."""
     out = _run("""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.models import moe as moe_lib
 
 cfg = dataclasses.replace(get_config('dbrx-132b', reduced=True),
                           dtype='float32')
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = auto_mesh((2, 4), ('data', 'model'))
 p = moe_lib.init_moe(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model), jnp.float32)
 out_local, aux_local = moe_lib.apply_moe(p, x, cfg, mesh=None,
@@ -102,6 +104,7 @@ def test_elastic_reshard_checkpoint():
     """Save on a 2x4 mesh, restore on 4x2 — mesh-agnostic checkpoints."""
     out = _run("""
 import dataclasses, jax, jax.numpy as jnp, tempfile, os
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.models import model
 from repro.dist.sharding import param_shardings
@@ -110,13 +113,13 @@ from repro.ckpt import save_checkpoint, load_checkpoint
 cfg = get_config('stablelm-1.6b', reduced=True)
 params = model.init_params(jax.random.PRNGKey(0), cfg)
 
-mesh1 = jax.make_mesh((2, 4), ('data', 'model'))
+mesh1 = auto_mesh((2, 4), ('data', 'model'))
 sh1 = param_shardings(jax.eval_shape(lambda: params), mesh1)
 p1 = jax.device_put(params, sh1)
 
 with tempfile.TemporaryDirectory() as d:
     save_checkpoint(os.path.join(d, 'ck'), p1, step=3)
-    mesh2 = jax.make_mesh((4, 2), ('data', 'model'))
+    mesh2 = auto_mesh((4, 2), ('data', 'model'))
     sh2 = param_shardings(jax.eval_shape(lambda: params), mesh2)
     tree, step, _ = load_checkpoint(os.path.join(d, 'ck'), params)
     p2 = jax.device_put(tree, sh2)
@@ -132,6 +135,7 @@ def test_seq_sharded_decode_correct():
     same decode logits as unsharded."""
     out = _run("""
 import dataclasses, jax, jax.numpy as jnp
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.models import model, Runtime
 from repro.dist.sharding import param_shardings, cache_shardings
@@ -145,7 +149,7 @@ caches = model.init_cache(cfg, B, S + 8)
 _, caches, clen = model.prefill(params, {'tokens': toks[:, :-1]}, cfg, rt0, caches)
 want, _, _ = model.decode_step(params, {'tokens': toks[:, -1:]}, cfg, rt0, caches, clen)
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = auto_mesh((2, 4), ('data', 'model'))
 rt1 = Runtime(mesh=mesh, attn_chunk=16, logit_chunk=16, remat='none')
 pshard = param_shardings(jax.eval_shape(lambda: params), mesh)
 cshard = cache_shardings(jax.eval_shape(lambda: caches), mesh)
@@ -166,6 +170,7 @@ def test_flash_decode_combine_matches_unsharded():
     """seq_shard_decode (shard_map partial-softmax combine) == plain decode."""
     out = _run("""
 import dataclasses, jax, jax.numpy as jnp
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.models import model, Runtime
 from repro.dist.sharding import param_shardings, cache_shardings
@@ -179,7 +184,7 @@ caches = model.init_cache(cfg, B, S + 8)
 _, caches, clen = model.prefill(params, {'tokens': toks[:, :-1]}, cfg, rt0, caches)
 want, _, _ = model.decode_step(params, {'tokens': toks[:, -1:]}, cfg, rt0, caches, clen)
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = auto_mesh((2, 4), ('data', 'model'))
 rt1 = Runtime(mesh=mesh, attn_chunk=16, logit_chunk=16, remat='none',
               seq_shard_decode=True)
 pshard = param_shardings(jax.eval_shape(lambda: params), mesh)

@@ -194,6 +194,17 @@ def test_exact_gt_ties_break_by_ascending_id():
                                                      metric))
 
 
+@pytest.mark.parametrize("k", [1, 10, 63, 64, 80])
+def test_stable_smallest_equals_stable_argsort(k):
+    """The partial selection behind the ground truth returns exactly the
+    stable full sort's prefix, ties at the k-th value included."""
+    from repro.anns.datasets import stable_smallest
+    rng = np.random.default_rng(k)
+    d = rng.integers(0, 12, size=(20, 64)).astype(np.float32)  # many ties
+    want = np.argsort(d, axis=1, kind="stable")[:, :k]
+    assert np.array_equal(stable_smallest(d, k), want)
+
+
 # ---------------------------------------------------------------------------
 # dataset attributes + filtered gt
 # ---------------------------------------------------------------------------

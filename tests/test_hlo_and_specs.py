@@ -39,13 +39,12 @@ def test_real_hlo_collectives_detected():
     script = """
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
-# jax 0.4.37: shard_map is not yet promoted to the jax namespace
-from jax.experimental.shard_map import shard_map
 from repro.dist.hlo import collective_bytes
-mesh = jax.make_mesh((2,), ("x",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2,), ("x",))
 def f(a):
     return jax.lax.psum(a, "x")
-fn = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+fn = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
 lowered = jax.jit(fn).lower(jax.ShapeDtypeStruct((8, 128), jnp.float32))
 c = lowered.compile()
 out = collective_bytes(c.as_text())
