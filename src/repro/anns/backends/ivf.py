@@ -88,6 +88,9 @@ def _ivf_search(centroids, cells, ids, base, base_q, scales, queries,
     position rows — one (B, nprobe*pad) rectangular candidate block —
     and score it densely (int8 dequant or fp32).  Stage 3: shortlist the
     best m by scan distance, fp32-rerank, remap positions to original ids.
+    Each stage runs under a named scope (``ivf.coarse``, ``ivf.scan``,
+    ``ivf.cut``, ``ivf.rerank``), which every op it lowers to carries in
+    its ``op_name`` metadata, so a device trace can time the stages apart.
 
     Pad slots (position -1) score BIG in the scan AND stay masked through
     the rerank (the validity mask travels with the shortlist), so they can
@@ -103,30 +106,35 @@ def _ivf_search(centroids, cells, ids, base, base_q, scales, queries,
     matching vector surface as id -1 (dist BIG).
     """
     B = queries.shape[0]
-    q32 = queries.astype(jnp.float32)
+    with jax.named_scope("ivf.coarse"):
+        q32 = queries.astype(jnp.float32)
+        dc = pairwise_distance(q32, centroids, metric=metric)  # (B, C)
+        _, probe = topk_smallest(dc, nprobe)                   # (B, nprobe)
 
-    dc = pairwise_distance(q32, centroids, metric=metric)      # (B, C)
-    _, probe = topk_smallest(dc, nprobe)                       # (B, nprobe)
+    with jax.named_scope("ivf.scan"):
+        cand = cells[probe].reshape(B, -1)                     # (B, nprobe*pad)
+        valid = cand >= 0
+        pos = jnp.where(valid, cand, 0)
+        if fmask is not None:
+            valid = valid & fmask[pos]
+        if quantized:
+            vecs = base_q[pos].astype(jnp.float32) * scales[pos][..., None]
+        else:
+            vecs = base[pos]
+        d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
+        d = jnp.where(valid, d, BIG)
+        scanned = jnp.sum(valid)
 
-    cand = cells[probe].reshape(B, -1)                         # (B, nprobe*pad)
-    valid = cand >= 0
-    pos = jnp.where(valid, cand, 0)
-    if fmask is not None:
-        valid = valid & fmask[pos]
-    if quantized:
-        vecs = base_q[pos].astype(jnp.float32) * scales[pos][..., None]
-    else:
-        vecs = base[pos]
-    d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
-    d = jnp.where(valid, d, BIG)
+    with jax.named_scope("ivf.cut"):
+        _, keep = jax.lax.top_k(-d, m)
+        short = jnp.take_along_axis(pos, keep, axis=1)         # (B, m)
+        short_valid = jnp.take_along_axis(valid, keep, axis=1)
 
-    _, keep = jax.lax.top_k(-d, m)
-    short = jnp.take_along_axis(pos, keep, axis=1)             # (B, m)
-    short_valid = jnp.take_along_axis(valid, keep, axis=1)
-    out_pos, out_d = fp32_rerank(base, q32, short, k=k, metric=metric,
-                                 valid=short_valid)
-    out_ids = jnp.where(out_d < BIG, ids[out_pos], -1)
-    return out_ids, out_d, jnp.sum(valid)
+    with jax.named_scope("ivf.rerank"):
+        out_pos, out_d = fp32_rerank(base, q32, short, k=k, metric=metric,
+                                     valid=short_valid)
+        out_ids = jnp.where(out_d < BIG, ids[out_pos], -1)
+    return out_ids, out_d, scanned
 
 
 @register("ivf")

@@ -23,6 +23,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.anns.api import (EF_LADDER, SearchParams, round_ef,
                             snap_down_to_ladder)
@@ -138,23 +139,31 @@ def execute_search_batch(search_fn, queries: np.ndarray,
     Returns ``(ids, dists, compute_s)`` with the pad rows already sliced
     off — ``compute_s`` is the wall-clock of the search itself, the
     number the queue-wait/compute latency split is built from.
+
+    Three profiler spans cover it: ``serve.dispatch`` (the pad, the
+    query upload and the jit dispatch), ``serve.wait`` (the device
+    finishing) and ``serve.d2h`` (the copies back to the host).
     """
     b, d = queries.shape
     if b > max_batch:
         raise ValueError(f"batch of {b} exceeds max_batch={max_batch}")
-    padded = queries.astype(np.float32, copy=False)
-    if b < max_batch:
-        padded = np.concatenate(
-            [padded, np.zeros((max_batch - b, d), np.float32)], axis=0)
-    t0 = time.perf_counter()
-    res = search_fn(padded, params)
-    jax.block_until_ready(res.ids)
+    with TraceAnnotation("serve.dispatch"):
+        padded = queries.astype(np.float32, copy=False)
+        if b < max_batch:
+            padded = np.concatenate(
+                [padded, np.zeros((max_batch - b, d), np.float32)], axis=0)
+        t0 = time.perf_counter()
+        res = search_fn(padded, params)
+    with TraceAnnotation("serve.wait"):
+        jax.block_until_ready(res.ids)
     compute_s = time.perf_counter() - t0
     # slice the pad rows off on the host: slicing the device array would
     # dispatch (and on first use, compile) a lax.slice per distinct b,
     # stalling the serve loop ~tens of ms whenever a new partial-batch
     # size shows up under load
-    return (np.asarray(res.ids)[:b], np.asarray(res.dists)[:b], compute_s)
+    with TraceAnnotation("serve.d2h"):
+        ids, dists = np.asarray(res.ids)[:b], np.asarray(res.dists)[:b]
+    return ids, dists, compute_s
 
 
 class AnnsServer:

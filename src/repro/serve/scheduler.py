@@ -27,6 +27,7 @@ import asyncio
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.anns.tune import DriftVerdict
 from repro.runtime.server import (batch_k_policy, execute_search_batch,
@@ -68,6 +69,8 @@ class ContinuousBatcher:
         #: caught up to this on re-arrival so banked credit can't starve
         #: the tenants that kept the server busy meanwhile
         self._vtime = 0.0
+        #: batches formed so far: the ``seq`` arg of each ``serve.batch``
+        self._batch_seq = 0
 
     # -- admission ----------------------------------------------------
 
@@ -143,51 +146,65 @@ class ContinuousBatcher:
     def step(self) -> int:
         """Shed expired requests, then form and execute one batch from
         the scheduled tenant's group.  Returns requests served (0 when
-        the queue held nothing live)."""
-        self._shed_expired()
-        state = self._pick_tenant()
-        if state is None:
-            return 0
-        batch = self.queue.pop_batch(state.group_key(), self.max_batch)
-        if not batch:
-            return 0
-        t_formed = self.clock()
-        queries = np.stack([r.query for r in batch])
-        kmax = max(r.k for r in batch)
-        k_batch = batch_k_policy(state.params.k, kmax,
-                                 index_size(self.target))
-        params = (state.params if k_batch == state.params.k
-                  else state.params.replace(k=k_batch))
-        try:
-            ids, dists, compute_s = execute_search_batch(
-                self._search, queries, params, max_batch=self.max_batch)
-        except BaseException as e:
-            # a failing batch must not strand its requests: the tickets
-            # were already popped, so resolve them with the error before
-            # propagating it to whoever drives the stepper
-            for r in batch:
-                self.telemetry.record_shed(r.tenant, "closed")
-                r.ticket.reject(e)
-            raise
-        t_done = self.clock()
-        for i, r in enumerate(batch):
-            kr = min(r.k, ids.shape[1])
-            queue_wait_ms = (t_formed - r.t_submit) * 1e3
-            total_ms = (t_done - r.t_submit) * 1e3
-            resp = ServeResponse(
-                ids=ids[i, :kr], dists=dists[i, :kr], tenant=r.tenant,
-                latency_ms=total_ms, queue_wait_ms=queue_wait_ms,
-                compute_ms=compute_s * 1e3)
-            self.telemetry.record_served(
-                r.tenant, queue_wait_ms=queue_wait_ms,
-                compute_ms=compute_s * 1e3, total_ms=total_ms)
-            self.tenants[r.tenant].advance()
-            r.ticket.resolve(resp)
-        self._vtime = max(self._vtime,
-                          *(t.pass_value for t in self.tenants.values()))
-        self.telemetry.record_batch()
-        self.telemetry.gauge_depth(self.queue.depth)
-        return len(batch)
+        the queue held nothing live).
+
+        A batch is one ``serve.batch`` profiler span (args ``seq``, the
+        batch number, and ``rows``) over ``serve.form``, the search
+        (``serve.dispatch``, ``serve.wait``, ``serve.d2h``, see
+        :func:`~repro.runtime.server.execute_search_batch`) and
+        ``serve.deliver``.  With no profiler trace active a span costs
+        a check and an object, and its args are never encoded."""
+        with TraceAnnotation("serve.batch") as span:
+            with TraceAnnotation("serve.form"):
+                self._shed_expired()
+                state = self._pick_tenant()
+                if state is None:
+                    return 0
+                batch = self.queue.pop_batch(state.group_key(),
+                                             self.max_batch)
+                if not batch:
+                    return 0
+                t_formed = self.clock()
+                queries = np.stack([r.query for r in batch])
+                kmax = max(r.k for r in batch)
+                k_batch = batch_k_policy(state.params.k, kmax,
+                                         index_size(self.target))
+                params = (state.params if k_batch == state.params.k
+                          else state.params.replace(k=k_batch))
+            self._batch_seq += 1
+            span.set_metadata(seq=self._batch_seq, rows=len(batch))
+            try:
+                ids, dists, compute_s = execute_search_batch(
+                    self._search, queries, params, max_batch=self.max_batch)
+            except BaseException as e:
+                # a failing batch must not strand its requests: the
+                # tickets were already popped, so resolve them with the
+                # error before propagating it to whoever drives the stepper
+                for r in batch:
+                    self.telemetry.record_shed(r.tenant, "closed")
+                    r.ticket.reject(e)
+                raise
+            with TraceAnnotation("serve.deliver"):
+                t_done = self.clock()
+                for i, r in enumerate(batch):
+                    kr = min(r.k, ids.shape[1])
+                    queue_wait_ms = (t_formed - r.t_submit) * 1e3
+                    total_ms = (t_done - r.t_submit) * 1e3
+                    resp = ServeResponse(
+                        ids=ids[i, :kr], dists=dists[i, :kr],
+                        tenant=r.tenant, latency_ms=total_ms,
+                        queue_wait_ms=queue_wait_ms,
+                        compute_ms=compute_s * 1e3)
+                    self.telemetry.record_served(
+                        r.tenant, queue_wait_ms=queue_wait_ms,
+                        compute_ms=compute_s * 1e3, total_ms=total_ms)
+                    self.tenants[r.tenant].advance()
+                    r.ticket.resolve(resp)
+                self._vtime = max(self._vtime, *(
+                    t.pass_value for t in self.tenants.values()))
+                self.telemetry.record_batch()
+                self.telemetry.gauge_depth(self.queue.depth)
+            return len(batch)
 
     def drain(self) -> int:
         """Serve until the queue is empty; returns total served.

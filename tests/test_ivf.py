@@ -4,6 +4,7 @@ invariants, the ``"ivf"`` backend's exact-anchor agreement at max nprobe
 on a >=10k-vector set, checkpoint shipping, and the backend-choice GRPO
 wiring."""
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -163,6 +164,38 @@ def test_pad_slots_never_displace_real_neighbors(blobs):
     ids = np.asarray(res.ids)
     for row in ids:
         assert len(set(row.tolist())) == 10, row      # k distinct ids
+
+
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "fp32"])
+def test_every_search_op_runs_under_one_stage_scope(blobs, quantized):
+    """Each op of the lowered search carries exactly one of the stage
+    scopes (``ivf.coarse``/``scan``/``cut``/``rerank``) in its
+    ``op_name``, so a device trace can time every stage apart.  Only
+    parameters, constants (and ops made of constants alone) and the
+    result tuple are exempt."""
+    v = dataclasses.replace(IVF_BASELINE, nlist=8, nprobe=2, kmeans_iters=2)
+    b = registry.create("ivf", v)
+    b.build(blobs[:600])
+    lowered = b.lower_search(blobs[:4], SearchParams(k=5, quantized=quantized))
+    text = lowered.compiler_ir("hlo").as_hlo_module().to_string()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[entry.index("{\n") + 2:entry.index("\n}")]
+    constant, checked = set(), 0
+    for line in entry.splitlines():
+        name, rhs = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        kind = re.search(r"\s([a-z][a-z-]*)\(", " " + rhs).group(1)
+        args = rhs[rhs.index(kind + "(") + len(kind) + 1:].split(")")[0]
+        operands = re.findall(r"%[\w.-]+", args)
+        if kind == "constant" or (operands and set(operands) <= constant):
+            constant.add(name)
+            continue
+        if kind in ("parameter", "tuple"):
+            continue
+        scopes = [part for op_name in re.findall(r'op_name="([^"]*)"', line)
+                  for part in op_name.split("/") if part.startswith("ivf.")]
+        assert len(scopes) == 1, line
+        checked += 1
+    assert checked > 20
 
 
 def test_nprobe_ladder_monotone():

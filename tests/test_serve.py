@@ -528,10 +528,17 @@ def test_tenant_default_deadline_applies(ds, ivf):
 # jit hygiene: continuous batching adds no retrace buckets
 # ---------------------------------------------------------------------------
 
-def test_continuous_batching_no_new_jit_buckets(ds, ivf):
+def test_continuous_batching_no_new_jit_buckets(ds, ivf, tmp_path):
     """Mixed partial batches (1..max_batch requests) all pad to the one
     compiled (max_batch, d) bucket at the tenant's params — zero new
-    traces once that bucket is warm."""
+    traces once that bucket is warm, profiler on or off.  Under a
+    profiler trace each batch is one ``serve.batch`` span carrying its
+    batch number and rows, with its five step spans inside it."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
     from repro.anns.backends.ivf import _ivf_search
 
     tenants = _tenants(TenantSpec("a"), TenantSpec("b"))
@@ -539,12 +546,31 @@ def test_continuous_batching_no_new_jit_buckets(ds, ivf):
     b.submit(ds.queries[0], "a")
     b.drain()                                     # warm the batch bucket
     before = _ivf_search._cache_size()
-    for size in (1, 3, 5, 8, 2, 7):               # every partial-batch size
-        for i in range(size):
-            b.submit(ds.queries[i % N_QUERY], "a" if i % 2 else "b")
-        b.drain()
+    sizes = (1, 3, 5, 8, 2, 7)                    # every partial-batch size
+    with jax.profiler.trace(str(tmp_path)):
+        for size in sizes:
+            for i in range(size):
+                b.submit(ds.queries[i % N_QUERY], "a" if i % 2 else "b")
+            b.drain()
     assert _ivf_search._cache_size() - before == 0
     assert b.telemetry.totals().accounted()
+
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+    spans = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("serve.")]
+    batches = sorted(s for s in spans if s[0] == "serve.batch")
+    assert [s[3]["rows"] for s in batches] == list(sizes)
+    assert [s[3]["seq"] for s in batches] == list(range(2, 2 + len(sizes)))
+    steps = ["serve.d2h", "serve.deliver", "serve.dispatch", "serve.form",
+             "serve.wait"]
+    for _, start, end, _ in batches:
+        inside = sorted(s[0] for s in spans if s[0] != "serve.batch"
+                        and start <= s[1] and s[2] <= end)
+        assert inside == steps
+    assert len(spans) == len(sizes) * (1 + len(steps))
 
 
 # ---------------------------------------------------------------------------
