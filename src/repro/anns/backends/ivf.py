@@ -1,11 +1,12 @@
 """``"ivf"`` backend: k-means cells + per-cell dense scans.
 
 Coarse stage routes through the Pallas ``pairwise_distance`` + ``topk``
-kernels (query x centroids), the probed cells are scanned as one
-rectangular gather over the cell-major layout (int8 codes by default,
-fp32 when ``SearchParams.quantized`` is explicitly ``False``), and the
-final answer comes from the standalone fp32 rerank stage shared with
-``backends/quantized.py``.
+kernels (query x centroids), the probed cells are scanned by the Pallas
+cell-scan kernel, which reads each probed cell's int8 codes as
+contiguous blocks of a chunk-aligned copy (fp32 rows gathered from the
+cell-major base when ``SearchParams.quantized`` is explicitly
+``False``), and the final answer comes from the standalone fp32 rerank
+stage shared with ``backends/quantized.py``.
 
 Jit hygiene: ``SearchParams.ef`` maps onto ``nprobe`` through a static
 ladder (:data:`NPROBE_LADDER`), mirroring the graph family's EF_LADDER
@@ -15,6 +16,7 @@ variant's ``nprobe``; other efs scale it proportionally before snapping.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -28,6 +30,7 @@ from repro.anns.backends.quantized import fp32_rerank
 from repro.anns.filters import AttributeColumns
 from repro.anns.ivf.layout import IvfIndex, build_ivf
 from repro.anns.registry import register
+from repro.kernels.cellscan.ops import cell_blocks, cell_scan
 from repro.kernels.distance.ops import pairwise_distance
 from repro.kernels.topk.ops import topk_smallest
 
@@ -78,16 +81,17 @@ def shortlist_width(params: SearchParams, k: int, n: int, nprobe: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "nprobe", "k", "m", "metric", "quantized"))
-def _ivf_search(centroids, cells, ids, base, base_q, scales, queries,
-                fmask=None, *,
+def _ivf_search(centroids, cells, ids, base, blocks, queries, fmask=None, *,
                 nprobe: int, k: int, m: int, metric: str, quantized: bool):
     """(B, d) queries -> (ids (B, k) original ids, dists (B, k) fp32).
 
     Stage 1 (coarse, Pallas kernels): distance matrix to centroids +
     top-nprobe cells.  Stage 2 (scan): gather the probed cells' padded
     position rows — one (B, nprobe*pad) rectangular candidate block —
-    and score it densely (int8 dequant or fp32).  Stage 3: shortlist the
-    best m by scan distance, fp32-rerank, remap positions to original ids.
+    and score it: the cell-scan kernel reads each probed cell's int8
+    codes from ``blocks`` (a :class:`CellBlocks`), or the fp32 rows are
+    gathered from ``base``.  Stage 3: shortlist the best m by scan
+    distance, fp32-rerank, remap positions to original ids.
     Each stage runs under a named scope (``ivf.coarse``, ``ivf.scan``,
     ``ivf.cut``, ``ivf.rerank``), which every op it lowers to carries in
     its ``op_name`` metadata, so a device trace can time the stages apart.
@@ -118,10 +122,10 @@ def _ivf_search(centroids, cells, ids, base, base_q, scales, queries,
         if fmask is not None:
             valid = valid & fmask[pos]
         if quantized:
-            vecs = base_q[pos].astype(jnp.float32) * scales[pos][..., None]
+            d = cell_scan(q32, blocks, probe, pad=cells.shape[1],
+                          metric=metric)
         else:
-            vecs = base[pos]
-        d = search_lib._qdist(q32, vecs, metric, quantized=quantized)
+            d = search_lib._qdist(q32, base[pos], metric)
         d = jnp.where(valid, d, BIG)
         scanned = jnp.sum(valid)
 
@@ -154,13 +158,20 @@ class IvfBackend(AttributeColumns):
         self.seed = seed
         self.index: IvfIndex | None = None
 
+    def _with_blocks(self, index: IvfIndex) -> IvfIndex:
+        """``index`` holding its int8 codes as the cell-scan kernel reads
+        them, copied once per layout (a subclass whose search scans
+        ``base_q`` itself returns ``index`` as it is)."""
+        return dataclasses.replace(index, blocks=cell_blocks(
+            index.base_q, index.scales, index.offsets, index.cell_pad))
+
     # -- AnnsIndex protocol ------------------------------------------------
     def build(self, base: np.ndarray) -> IvfIndex:
         v = self.variant
-        self.index = build_ivf(base, nlist=v.nlist,
-                               kmeans_iters=v.kmeans_iters,
-                               metric=self.metric, seed=self.seed,
-                               max_cell=getattr(v, "max_cell", 0) or None)
+        self.index = self._with_blocks(build_ivf(
+            base, nlist=v.nlist, kmeans_iters=v.kmeans_iters,
+            metric=self.metric, seed=self.seed,
+            max_cell=getattr(v, "max_cell", 0) or None))
         self.attributes = None       # columns describe one base layout
         self._clear_filter_caches()
         return self.index
@@ -203,8 +214,8 @@ class IvfBackend(AttributeColumns):
         quantized = True if params.quantized is None else bool(params.quantized)
         fmask = (self._row_mask_dev(p.filter)
                  if p.filter is not None else None)
-        args = (idx.centroids, idx.cells, idx.ids, idx.base, idx.base_q,
-                idx.scales, jnp.asarray(queries, jnp.float32), fmask)
+        args = (idx.centroids, idx.cells, idx.ids, idx.base, idx.blocks,
+                jnp.asarray(queries, jnp.float32), fmask)
         statics = dict(nprobe=nprobe, k=k, m=m, metric=self.metric,
                        quantized=quantized)
         return args, statics
@@ -229,7 +240,7 @@ class IvfBackend(AttributeColumns):
         if idx is None:
             return 0
         arrays = (idx.centroids, idx.cells, idx.ids, idx.base, idx.base_q,
-                  idx.scales)
+                  idx.scales, *(idx.blocks or ()))
         return (sum(a.size * a.dtype.itemsize for a in arrays)
                 + idx.offsets.nbytes)
 
@@ -252,7 +263,7 @@ class IvfBackend(AttributeColumns):
 
     def from_state_dict(self, state: dict) -> None:
         self.metric = state["metric"]
-        self.index = IvfIndex(
+        self.index = self._with_blocks(IvfIndex(
             centroids=jnp.asarray(state["centroids"]),
             cells=jnp.asarray(state["cells"]),
             ids=jnp.asarray(state["ids"]),
@@ -260,5 +271,5 @@ class IvfBackend(AttributeColumns):
             base_q=jnp.asarray(state["base_q"]),
             scales=jnp.asarray(state["scales"]),
             offsets=np.asarray(state["offsets"]),
-            metric=state["metric"])
+            metric=state["metric"]))
         self._restore_attr_leaves(state)

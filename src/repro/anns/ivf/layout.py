@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.anns.ivf.kmeans import assign, kmeans_fit, split_oversized
+from repro.kernels.cellscan.ops import CellBlocks, chunk_rows, live_chunks
 from repro.kernels.common import round_up
 from repro.kernels.qdist.ops import quantize_int8
 
@@ -53,6 +54,10 @@ class IvfIndex:
     scales: jax.Array          # (N,) f32 dequant scales
     offsets: np.ndarray        # (C+1,) int64 CSR cell boundaries (host)
     metric: str                # "l2" | "ip"
+    #: the int8 codes copied chunk-aligned for the cell-scan kernel
+    #: (``kernels.cellscan.ops.cell_blocks``); held by the ``ivf``
+    #: backend's layouts, None where the search gathers ``base_q``
+    blocks: CellBlocks | None = None
 
     @property
     def n(self) -> int:
@@ -165,4 +170,16 @@ def ivf_stats(index: IvfIndex) -> dict:
         # the balanced-assignment cap (build_ivf max_cell) bounds; an
         # empty index has no skew, a single non-empty cell has skew 1
         "cell_skew": float(biggest / mean) if mean > 0 else 0.0,
+        # rows the cell-scan kernel reads per probed cell (its live
+        # chunks) over the pad it used to gather: what skipping the dead
+        # chunks saves; 1 where a chunk spans the whole pad
+        "scan_read_share": _scan_read_share(index, counts),
     }
+
+
+def _scan_read_share(index: IvfIndex, counts: np.ndarray) -> float:
+    if not counts.size:
+        return 0.0
+    chunk = chunk_rows(int(index.base.shape[1]), index.cell_pad)
+    live = live_chunks(index.offsets, chunk)
+    return float(np.mean(live * chunk) / index.cell_pad)

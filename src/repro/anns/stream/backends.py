@@ -638,6 +638,9 @@ class StreamingIvfBackend(_StreamCommon, IvfBackend):
     def _tail_shape_for(self, index) -> tuple:
         return (self.tail_cap,)
 
+    def _with_blocks(self, index):
+        return index        # stream_ivf_search scans base_q itself
+
     def _global_base(self):
         return (np.asarray(self.index.base),
                 np.asarray(self.index.ids))

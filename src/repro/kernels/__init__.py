@@ -11,6 +11,8 @@ Kernels:
   maintenance).
 - ``qdist``    — int8 symmetric-quantized asymmetric distance (refinement
   module's preliminary search).
+- ``cellscan`` — the IVF int8 cell scan: each probed cell's codes read as
+  contiguous chunks through scalar-prefetched block tables.
 - ``flash``    — causal flash attention forward (policy-LM serving path;
   window + logit-softcap support).
 """

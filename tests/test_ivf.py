@@ -136,6 +136,23 @@ def test_layout_invariants(blobs):
     assert stats["n"] == len(blobs) and stats["nlist"] == 32
 
 
+@pytest.mark.parametrize("d,want", [
+    # chunk 256 rows: the 257-row cell reads two chunks, the empty none
+    (960, (256 + 512 + 0 + 256) / 4 / 264),
+    # one 384-row chunk (the pad rounded up to a lane tile) per live cell
+    (128, (384 + 384 + 0 + 384) / 4 / 264),
+])
+def test_ivf_stats_scan_read_share(d, want):
+    from repro.anns.ivf.layout import layout_from_assignments
+    sizes = np.array([256, 257, 0, 10])
+    a = np.repeat(np.arange(4), sizes)
+    x = np.random.default_rng(0).standard_normal((len(a), d))
+    idx = layout_from_assignments(x, a, np.zeros((4, d), np.float32),
+                                  metric="l2")
+    assert idx.cell_pad == 264
+    assert ivf_stats(idx)["scan_read_share"] == pytest.approx(want)
+
+
 def test_small_probed_block_still_returns_k(blobs):
     """Regression: nprobe=1 over tiny cells used to hand fp32_rerank a
     shortlist narrower than k (top_k ValueError).  The backend must widen

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.cellscan import ops as cellscan_ops
 from repro.kernels.distance import ops as distance_ops
 from repro.kernels.topk import ops as topk_ops
 
@@ -48,7 +49,7 @@ def chip_kernels(one_chip):
     Jit caches are cleared on both sides: the interpret choice is made at
     trace time and a cached CPU trace would otherwise be reused."""
     mp = pytest.MonkeyPatch()
-    for mod in (distance_ops, topk_ops):
+    for mod in (cellscan_ops, distance_ops, topk_ops):
         mp.setattr(mod, "interpret_default", lambda: False)
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -89,22 +90,62 @@ def test_distance_compiles_at_brute_force_chunk(chip_kernels, dim, exact):
     _assert_kernel(fn.lower(q, x).compile())
 
 
-def test_ivf_search_compiles_at_sift_1m(chip_kernels):
+def _compile_ivf_search(s, *, d: int, nlist: int, b: int):
+    """``_ivf_search`` at 10^6 vectors, one batch of ``b`` queries.  The
+    cell blocks take their most chunks: every cell wastes under one."""
     from repro.anns.backends.ivf import _ivf_search
-    s = chip_kernels
-    args = (_spec((NLIST, D), jnp.float32, s),          # centroids
-            _spec((NLIST, CELL_PAD), jnp.int32, s),     # cells
+    chunk = cellscan_ops.chunk_rows(d, CELL_PAD)
+    n_chunks = -(-N // chunk) + nlist
+    blocks = cellscan_ops.CellBlocks(
+        codes=_spec((n_chunks, d, chunk), jnp.int8, s),
+        rows=_spec((n_chunks, 2, chunk), jnp.float32, s),
+        first=_spec((nlist,), jnp.int32, s),
+        live=_spec((nlist,), jnp.int32, s))
+    args = (_spec((nlist, d), jnp.float32, s),          # centroids
+            _spec((nlist, CELL_PAD), jnp.int32, s),     # cells
             _spec((N,), jnp.int32, s),                  # ids
-            _spec((N, D), jnp.float32, s),              # base
-            _spec((N, D), jnp.int8, s),                 # base_q
-            _spec((N,), jnp.float32, s),                # scales
-            _spec((B, D), jnp.float32, s))              # queries
+            _spec((N, d), jnp.float32, s),              # base
+            blocks,
+            _spec((b, d), jnp.float32, s))              # queries
     compiled = _ivf_search.lower(*args, nprobe=NPROBE, k=K, m=2 * K,
                                  metric="l2", quantized=True).compile()
     _assert_kernel(compiled)
+    assert "%cell_scan_blocks" in compiled.as_text()
     mem = compiled.memory_analysis()
+    return mem.argument_size_in_bytes + mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("d,nlist", [(D, 1113), (960, 1383)])
+def test_cell_blocks_compile_at_1m(chip_kernels, d, nlist):
+    """The build's two programs for the scan's blocks: the chunked copy
+    (its shape follows the base alone) and the alignment kernel (one per
+    layout), at 10^6 vectors; each cell takes at most two chunks."""
+    from repro.kernels.cellscan.cellscan import align_blocks
+    s = chip_kernels
+    chunk = cellscan_ops.chunk_rows(d, CELL_PAD)
+    m = N // chunk + 2
+    cellscan_ops._chunked.lower(_spec((N, d), jnp.int8, s),
+                                _spec((N,), jnp.float32, s),
+                                chunk=chunk, dp=d).compile()
+    blocks = 2 * nlist
+    compiled = align_blocks.lower(
+        _spec((blocks,), jnp.int32, s), _spec((blocks,), jnp.int32, s),
+        _spec((m, d, chunk), jnp.int8, s),
+        _spec((m, 2, chunk), jnp.float32, s)).compile()
+    _assert_kernel(compiled)
+
+
+def test_ivf_search_compiles_at_sift_1m(chip_kernels):
     # index + one batch's scan must fit one 16 GB chip with room to spare
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9, mem
+    assert _compile_ivf_search(chip_kernels, d=D, nlist=NLIST, b=B) < 4e9
+
+
+def test_ivf_search_compiles_at_gist_1m(chip_kernels):
+    """GIST-960: 1,383 cells after the ``max_cell`` splits, batch 32.
+    The float32 base (3.84 GB) is an argument and, relaid out for the
+    rerank in every batch, a temporary as well; the scan adds no
+    per-batch block of dequantised rows."""
+    assert _compile_ivf_search(chip_kernels, d=960, nlist=1383, b=32) < 10e9
 
 
 @pytest.fixture(scope="module")
