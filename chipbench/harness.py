@@ -80,9 +80,10 @@ class Run:
     def probe(self) -> np.ndarray:
         """(n_pool, nprobe) cells each pool query probes (reference)."""
         if self._probe is None:
+            st = self.system.stated(self.config)
             self._probe = self.system.reference.probes(
-                self.partition, self.data.queries,
-                self.system.stated(self.config)["nprobe"])
+                self.partition, self.data.queries, st["nprobe"],
+                metric=st["metric"])
         return self._probe
 
 

@@ -8,6 +8,12 @@ symmetric per-vector int8 codes and scales, the coarse route, the cell
 scan, the shortlist cut, the exact rerank and the ids.  Ties break by
 lowest index at every cut (stable sorts), as the kernels do.
 
+``metric`` is the configuration's, as ``systems/<system>.py:stated``
+maps it, never the program's: ``"l2"`` ranks by squared l2 distance,
+``"ip"`` (an angular deployment's unit vectors) by ``-q.x``, at every
+stage: the coarse route over the centroids, the scan over the
+dequantised codes and the exact rerank.
+
 ``rerank="bf16"`` is the control: the same search with the rerank's dot
 product taken as one bfloat16 pass with float32 accumulation, which is
 what a float32 dot at default precision does on a TPU.  The configuration
@@ -53,13 +59,30 @@ def probe_floor(sizes: np.ndarray, k: int) -> int:
     return int(np.searchsorted(cum, min(k, int(cum[-1]))) + 1)
 
 
-def probes(partition: Partition, queries: np.ndarray,
-           nprobe: int) -> np.ndarray:
+def _metric(metric: str) -> str:
+    if metric not in ("l2", "ip"):
+        raise ValueError(f"reference metric must be l2 or ip, not "
+                         f"{metric!r}")
+    return metric
+
+
+def _dist(rows: np.ndarray, q: np.ndarray, metric: str) -> np.ndarray:
+    """float64 distance of each row to ``q``: squared l2, or ``-q.x``."""
+    if _metric(metric) == "ip":
+        return -(rows @ q)
+    return ((rows - q) ** 2).sum(1)
+
+
+def probes(partition: Partition, queries: np.ndarray, nprobe: int, *,
+           metric: str) -> np.ndarray:
     """(nq, nprobe) cells each query probes: float64 coarse route."""
     c = partition.centroids.astype(np.float64)
     q = np.asarray(queries, np.float64)
-    d = ((q * q).sum(1)[:, None] + (c * c).sum(1)[None, :]
-         - 2.0 * q @ c.T)
+    if _metric(metric) == "ip":
+        d = -(q @ c.T)
+    else:
+        d = ((q * q).sum(1)[:, None] + (c * c).sum(1)[None, :]
+             - 2.0 * q @ c.T)
     return np.argsort(d, axis=1, kind="stable")[:, :nprobe]
 
 
@@ -74,7 +97,7 @@ SCAN_BLOCK = 2048
 
 
 def search_one(q: np.ndarray, probe: np.ndarray, partition: Partition,
-               base: np.ndarray, *, m: int, k: int,
+               base: np.ndarray, *, m: int, k: int, metric: str,
                rerank: str = "f64") -> tuple:
     """One query through probe -> int8 scan -> shortlist of m -> rerank
     -> top k.  Returns (ids (k,), dists (k,))."""
@@ -84,16 +107,19 @@ def search_one(q: np.ndarray, probe: np.ndarray, partition: Partition,
     for lo in range(0, len(cand), SCAN_BLOCK):
         codes, scale = quantize(base[cand[lo:lo + SCAN_BLOCK]])
         deq = codes * scale.astype(np.float64)[:, None]
-        scan[lo:lo + SCAN_BLOCK] = ((deq - q64) ** 2).sum(1)
+        scan[lo:lo + SCAN_BLOCK] = _dist(deq, q64, metric)
     short = np.argsort(scan, kind="stable")[:min(m, len(cand))]
     srows = base[cand[short]]
     if rerank == "f64":
-        exact = ((srows.astype(np.float64) - q64) ** 2).sum(1)
+        exact = _dist(srows.astype(np.float64), q64, metric)
     elif rerank == "bf16":
         qf = q.astype(np.float32)
         dots = _bf16(srows) @ _bf16(qf)
-        exact = ((qf * qf).sum() + (srows * srows).sum(1) - 2.0 * dots
-                 ).astype(np.float64)
+        if metric == "ip":
+            exact = (-dots).astype(np.float64)
+        else:
+            exact = ((qf * qf).sum() + (srows * srows).sum(1) - 2.0 * dots
+                     ).astype(np.float64)
     else:
         raise ValueError(f"unknown rerank {rerank!r}")
     top = np.argsort(exact, kind="stable")[:k]
@@ -104,17 +130,21 @@ def search_one(q: np.ndarray, probe: np.ndarray, partition: Partition,
 NUMBERS = ("unanswered", "bad_answers", "probe_floor", "dist_err", "id_miss")
 
 
-def dist_errors(answers: list, queries: np.ndarray,
-                base: np.ndarray) -> np.ndarray:
+def dist_errors(answers: list, queries: np.ndarray, base: np.ndarray, *,
+                metric: str) -> np.ndarray:
     """Per answer: the worst gap between a served distance and the
     float64 distance of the served id to that request's own query, over
-    ``|q|^2 + |x|^2`` (the scale an l2 expansion rounds at)."""
+    the scale its float32 arithmetic rounds at: ``|q|^2 + |x|^2`` for an
+    l2 expansion, ``|q|*|x|`` for an ip dot."""
     out = np.empty(len(answers))
     for j, (qi, ids, dists) in enumerate(answers):
         q = queries[qi].astype(np.float64)
         x = base[ids].astype(np.float64)
-        d64 = ((x - q) ** 2).sum(1)
-        scale = (q * q).sum() + (x * x).sum(1)
+        d64 = _dist(x, q, metric)
+        if metric == "ip":
+            scale = np.sqrt((q * q).sum()) * np.sqrt((x * x).sum(1))
+        else:
+            scale = (q * q).sum() + (x * x).sum(1)
         out[j] = np.max(np.abs(np.asarray(dists, np.float64) - d64) / scale)
     return out
 
@@ -130,7 +160,7 @@ def bad_answer(ids: np.ndarray, dists: np.ndarray, n: int, k: int) -> bool:
 
 def compare(answers: list, n_unanswered: int, queries: np.ndarray,
             base: np.ndarray, partition: Partition, *, nprobe: int,
-            m: int, k: int, sample: np.ndarray) -> dict:
+            m: int, k: int, metric: str, sample: np.ndarray) -> dict:
     """The numbers ``correct`` is decided by.
 
     ``answers``: (query index, served ids, served dists) of every answered
@@ -146,16 +176,18 @@ def compare(answers: list, n_unanswered: int, queries: np.ndarray,
     bad = {j for j, (_, ids, d) in enumerate(answers)
            if bad_answer(ids, d, n, k)}
     good = [a for j, a in enumerate(answers) if j not in bad]
-    errs = dist_errors(good, queries, base) if good else np.zeros(1)
+    errs = dist_errors(good, queries, base, metric=metric) if good \
+        else np.zeros(1)
     picked = [answers[j] for j in sample if j not in bad]
     qis = np.array([a[0] for a in picked], np.int64)
     miss = []
     if len(picked):
-        pr = probes(partition, queries[qis], nprobe)
+        pr = probes(partition, queries[qis], nprobe, metric=metric)
         with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as ex:
             refs = list(ex.map(
                 lambda a: search_one(queries[a[0]], a[1], partition, base,
-                                     m=m, k=k), zip(qis, pr)))
+                                     m=m, k=k, metric=metric),
+                zip(qis, pr)))
         miss = [1.0 - len(np.intersect1d(ids, ref[0])) / k
                 for (_, ids, _), ref in zip(picked, refs)]
     return {"unanswered": float(n_unanswered), "bad_answers": float(len(bad)),
@@ -166,11 +198,11 @@ def compare(answers: list, n_unanswered: int, queries: np.ndarray,
 
 def control_answers(picked: list, queries: np.ndarray, base: np.ndarray,
                     partition: Partition, *, nprobe: int, m: int,
-                    k: int) -> list:
+                    k: int, metric: str) -> list:
     """The control put in the program's place: its answers to the same
     requests, from the bf16 rerank."""
     qis = np.array([a[0] for a in picked], np.int64)
-    pr = probes(partition, queries[qis], nprobe)
+    pr = probes(partition, queries[qis], nprobe, metric=metric)
     return [(qi, *search_one(queries[qi], p, partition, base, m=m, k=k,
-                             rerank="bf16"))
+                             metric=metric, rerank="bf16"))
             for qi, p in zip(qis, pr)]

@@ -4,6 +4,18 @@
 
 The configuration's ``index`` block gives the ``IVF_BASELINE`` knobs;
 ``SearchParams(ef=64)`` probes exactly ``index.nprobe`` cells.
+
+The data block's metric maps to the backend's by :data:`METRICS`, and
+any other raises: the backend ranks every metric that is not ``"ip"`` by
+l2, so an unmapped name would be served as l2 in silence.  What the
+system guarantees: every admitted request is answered with ``k``
+distinct original ids and their float32 rerank distances, ascending.  An
+l2 deployment's served distance is the squared l2 distance; an angular
+deployment's is the float32 ``-q.x`` of its unit vectors, so
+ANN-Benchmarks' angular distance (one minus the cosine) is one plus it.
+Those vectors come normalized from ``chipbench/data.py``: the benchmark
+does the step that ANN-Benchmarks leaves to the system, and the served
+path never normalizes.
 """
 from __future__ import annotations
 
@@ -17,9 +29,20 @@ from chipbench.references import ivf as reference
 
 #: the jitted search as it is named in a device trace's "XLA Modules"
 SEARCH_MODULE = "jit__ivf_search"
+#: the data block's metric -> the backend's (and the reference's)
+METRICS = {"l2": "l2", "angular": "ip"}
 #: the coarse route's Pallas kernels (distance, top-k), by the instruction
 #: name their ops carry in a device trace (read by hand on a v5e trace)
 COARSE_KERNELS = ("%distance", "%topk_smallest")
+
+
+def backend_metric(cfg: dict) -> str:
+    """The backend's metric for the configuration's data block."""
+    name = cfg["data"]["metric"]
+    if name not in METRICS:
+        raise ValueError(f"the ivf system serves the metrics "
+                         f"{sorted(METRICS)}, not {name!r}")
+    return METRICS[name]
 
 
 def variant(cfg: dict):
@@ -40,7 +63,7 @@ def build(cfg: dict, base: np.ndarray, seed: int):
     """The built backend and the host seconds around ``build`` (k-means
     and layout), ended by a device sync."""
     from repro.anns import registry
-    backend = registry.create("ivf", variant(cfg), metric=cfg["data"]["metric"],
+    backend = registry.create("ivf", variant(cfg), metric=backend_metric(cfg),
                               seed=int(seed) % 2**63)
     t0 = time.perf_counter()
     idx = backend.build(base)
@@ -67,11 +90,12 @@ def partition(backend) -> reference.Partition:
 
 
 def stated(cfg: dict) -> dict:
-    """(nprobe, m, k) as the configuration states them: the reference
-    takes them from there, never from the program."""
+    """(nprobe, m, k, metric) as the configuration states them: the
+    reference takes them from there, never from the program."""
     k = cfg["serve"]["k"]
     return {"nprobe": cfg["index"]["nprobe"],
-            "m": max(k, cfg["index"]["rerank_factor"] * k), "k": k}
+            "m": max(k, cfg["index"]["rerank_factor"] * k), "k": k,
+            "metric": backend_metric(cfg)}
 
 
 def limits(cfg: dict) -> dict:

@@ -15,3 +15,6 @@ TINY = {"data": {"n": 4096, "d": 32, "n_query": 64, "clusters": 4,
                  "intrinsic_dim": 4},
         "index": {"nlist": 16, "nprobe": 4, "kmeans_iters": 4},
         "serve": {"max_batch": 8}, "check": {"sample": 32}}
+#: TINY made angular: unit vectors ranked by -q.x, ``n`` not a multiple of
+#: ``clusters``
+ANGULAR = {"data": {"metric": "angular", "n": 4099}}

@@ -15,8 +15,24 @@ def test_reference_at_every_cell_is_the_brute_force_anchor():
     every = np.arange(len(part.members))
     for qi, q in enumerate(dat.queries):
         ids, dists = ref.search_one(q, every, part, dat.base,
-                                    m=len(dat.base), k=10)
+                                    m=len(dat.base), k=10, metric="l2")
         assert set(ids) == set(want[qi]), qi
+        assert np.all(np.diff(dists) >= 0)
+
+
+def test_ip_reference_at_every_cell_is_a_float64_brute_force():
+    """An angular build: at nprobe = nlist and a shortlist of the whole
+    base, the ip reference gives the k largest float64 ``q.x``."""
+    cfg, system, dat, _, part = built(angular=True)
+    assert system.stated(cfg)["metric"] == "ip"
+    b = dat.base.astype(np.float64)
+    every = np.arange(len(part.members))
+    for qi, q in enumerate(dat.queries):
+        want = -(b @ q.astype(np.float64))
+        ids, dists = ref.search_one(q, every, part, dat.base,
+                                    m=len(dat.base), k=10, metric="ip")
+        assert set(ids) == set(np.argsort(want, kind="stable")[:10]), qi
+        np.testing.assert_allclose(dists, want[ids], rtol=0, atol=1e-12)
         assert np.all(np.diff(dists) >= 0)
 
 
@@ -29,7 +45,8 @@ def test_partition_covers_the_base_once():
 def test_work_count_matches_a_brute_count():
     cfg, system, dat, _, part = built()
     st = system.stated(cfg)
-    probe = ref.probes(part, dat.queries, st["nprobe"])
+    probe = ref.probes(part, dat.queries, st["nprobe"],
+                       metric=st["metric"])
     d = cfg["data"]["d"]
     batch = [3, 5, 9, 3, 17, 40, 41, 63]
     cells, per_query = set(), 0

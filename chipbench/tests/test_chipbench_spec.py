@@ -70,7 +70,8 @@ def test_metrics_follow_their_cells():
                              if m.name in layer)
     assert {m.name for m in bench.metrics_for("sift1m.closed", True)} == {
         "device_idle_share", "search_device_ms", "search_roofline",
-        "coarse_kernels_ms", "batch_compute_ms.qps"}
+        "coarse_kernels_ms", "batch_compute_ms.qps", "tier_host_ms.qps",
+        "idle_in_tier_ms", "scan_device_ms", "rerank_device_ms"}
     for name in bench.listing()["metrics"]:
         bench.reader(name)
 

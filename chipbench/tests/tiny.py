@@ -1,18 +1,21 @@
-"""A tiny build of the sift1m configuration, shared by the CPU tests."""
+"""A tiny build of the sift1m configuration, l2 or made angular, shared
+by the CPU tests."""
 from __future__ import annotations
 
 import functools
 
 from chipbench import data as data_lib
 from chipbench import harness, spec
-from chipbench.tests.conftest import TINY
+from chipbench.tests.conftest import ANGULAR, TINY
 
 
 @functools.lru_cache(maxsize=None)
-def built(seed: int = 0):
+def built(seed: int = 0, angular: bool = False):
     """(config, system, data, backend, partition) of a tiny build."""
     bench = spec.Bench()
     cfg = harness._merge(bench.config("sift1m"), TINY)
+    if angular:
+        cfg = harness._merge(cfg, ANGULAR)
     system = bench.system(cfg["system"])
     dat = data_lib.make(cfg["data"], seed, cfg["serve"]["k"])
     backend, _ = system.build(cfg, dat.base, seed)
